@@ -30,6 +30,7 @@ from ..configs.base import ModelConfig
 from ..models import get_family, moe as moe_mod
 from ..nn import layers as nn
 from ..nn import spec as nnspec
+from ..telemetry import TELEMETRY
 from .arena import InstanceArena
 
 
@@ -123,23 +124,42 @@ class LazyParams:
 
     def fault_all(self, skip_prefixes: tuple[str, ...] = (),
                   embed_rows: np.ndarray | None = None) -> None:
-        for p in self.paths:
-            full = f"params/{p}"
-            if any(p.startswith(s) for s in skip_prefixes):
-                continue
-            if embed_rows is not None and p == "embed/table":
-                self.arena.tensor_rows(full, embed_rows.tolist(),
-                                       parallel=self.parallel)
-            else:
-                self.arena.touch_pages(
-                    self.arena.layout.pages_of(full), parallel=self.parallel)
+        with TELEMETRY.span("executor.touch") as span:
+            n = 0
+            for p in self.paths:
+                if any(p.startswith(s) for s in skip_prefixes):
+                    continue
+                e = self.arena.layout.entries[f"params/{p}"]
+                if embed_rows is not None and p == "embed/table":
+                    pages = sorted(e.row_pages(embed_rows.tolist()))
+                else:
+                    pages = e.pages()
+                self.arena.touch_pages(pages, parallel=self.parallel)
+                n += len(pages)
+            span.set_metadata(pages=n)
 
     def tree(self) -> Any:
-        """Full param tree as jnp arrays (zero-filled where never faulted)."""
-        return nnspec.map_leaves(
-            lambda p, s: jax.device_put(
-                _np(self.arena, f"params/{p}", fault=False), self.device),
-            self.specs)
+        """Full param tree as jnp arrays (zero-filled where never faulted),
+        ready on the device: every caller runs the forward on it next."""
+        with TELEMETRY.span("executor.placement") as span:
+            tree = place(self.arena, self.specs, self.device)
+            jax.block_until_ready(tree)
+            span.set_metadata(bytes=placed_bytes(tree))
+        return tree
+
+
+def place(arena: InstanceArena, specs, device) -> Any:
+    """Put every parameter of ``specs`` on ``device`` from the arena's
+    pages as they stand (no fault)."""
+    return nnspec.map_leaves(
+        lambda p, s: jax.device_put(_np(arena, f"params/{p}", fault=False),
+                                    device),
+        specs)
+
+
+def placed_bytes(tree) -> int:
+    """The sum of the placed leaves' ``nbytes``."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 def _touch_infra(arena: InstanceArena) -> None:
@@ -175,7 +195,9 @@ def run_invocation(cfg: ModelConfig, arena: InstanceArena, batch: dict, *,
     if cfg.family != "moe":
         lp.fault_all(embed_rows=embed_rows)
         params = lp.tree()
-        logits = _jit_forward(cfg, params, batch)
+        with TELEMETRY.span("executor.forward"):
+            logits = _jit_forward(cfg, params, batch)
+            logits.block_until_ready()
         return logits, time.perf_counter() - t0
 
     # ---- MoE: interleave routing with expert faulting ---------------------

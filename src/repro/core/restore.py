@@ -219,25 +219,36 @@ def fuse_ws_block(pages, data: bytes, *, engine: str = "auto"
     interpreted on the CPU backend only); ``engine='numpy'`` is the
     vectorized host path.  Both produce identical bytes (tested).
     """
-    idx = np.asarray(pages, dtype=np.int64)
-    ws = np.frombuffer(data, dtype=np.uint8,
-                       count=len(idx) * PAGE).reshape(len(idx), PAGE)
-    order = np.argsort(idx, kind="stable")
     if engine == "auto":
         engine = default_fuse_engine()
-    if engine == "pallas":
+    if engine not in ("pallas", "numpy"):
+        raise ValueError(f"unknown fuse engine {engine!r}")
+    span = TELEMETRY.span
+    with span("restore.fuse", engine=engine, bytes=len(pages) * PAGE):
+        idx = np.asarray(pages, dtype=np.int64)
+        ws = np.frombuffer(data, dtype=np.uint8,
+                           count=len(idx) * PAGE).reshape(len(idx), PAGE)
+        order = np.argsort(idx, kind="stable")
+        if engine == "numpy":
+            return idx[order], np.ascontiguousarray(ws[order])
         import jax.numpy as jnp
 
         from ..kernels import gather_pages
-        # a page is 1024 32-bit words: exactly one native (8, 128) tile
-        words = gather_pages(jnp.asarray(ws.view(np.uint32)),
-                             jnp.asarray(order.astype(np.int32)))
-        block = np.asarray(words).view(np.uint8)
-    elif engine == "numpy":
-        block = np.ascontiguousarray(ws[order])
-    else:
-        raise ValueError(f"unknown fuse engine {engine!r}")
-    return idx[order], block
+        # each child ends when its result is ready: the next step waits
+        # for that result anyway (the kernel for its inputs, the copy back
+        # for the kernel)
+        with span("restore.fuse.h2d", bytes=ws.nbytes):
+            # a page is 1024 32-bit words: exactly one native (8, 128) tile
+            src = jnp.asarray(ws.view(np.uint32))
+            perm = jnp.asarray(order.astype(np.int32))
+            src.block_until_ready()
+            perm.block_until_ready()
+        with span("restore.fuse.gather", bytes=ws.nbytes):
+            words = gather_pages(src, perm)
+            words.block_until_ready()
+        with span("restore.fuse.d2h", bytes=ws.nbytes):
+            block = np.asarray(words).view(np.uint8)
+        return idx[order], block
 
 
 class RestorePipeline:
@@ -267,7 +278,8 @@ class RestorePipeline:
         self.connector = connector
         self.clock = clock
         self.registry = TELEMETRY if registry is None else registry
-        self._trace = self.registry.trace("cold_start", base=base)
+        #: this pipeline's index in its restore group (a span attribute)
+        self.member = 0
         self.timings = StageTimings()
         self.gm: GuestMemoryFile | None = None
         self.monitor: Monitor | None = None
@@ -278,12 +290,10 @@ class RestorePipeline:
         self._split_k: int | None = None
         self._tail_fetch = None          # () -> (pages, data) full WS
 
-    def _span(self, stage: str, t0: float, dur_s: float, **attrs) -> None:
-        """Record one stage span in the cold-start trace.  ``dur_s`` is
-        always the value just written to ``self.timings`` — StageTimings
-        stays the single stage-seconds sink (REP005); the trace only
-        mirrors it for per-invocation attribution."""
-        self._trace.add(stage, t0, dur_s, **attrs)
+    def _observe(self, stage: str, dur_s: float) -> None:
+        """Feed one stage's seconds to its ``restore.<stage>_s`` histogram.
+        ``dur_s`` is always the value just written to ``self.timings`` —
+        StageTimings stays the single stage-seconds sink (REP005)."""
         self.registry.observe(f"restore.{stage}_s", dur_s)
 
     # -- stages ---------------------------------------------------------
@@ -296,20 +306,23 @@ class RestorePipeline:
         restore parses the manifest once and shares it.
         """
         t0 = self.clock()
-        self.gm = (GuestMemoryFile(self.base, layout) if layout is not None
-                   else GuestMemoryFile.open(self.base))
-        self.monitor = Monitor(self.gm, self.base, self.reap,
-                               mode=self.mode, cache=self.cache)
-        if self.exec_restore is not None:
-            self.exec_restore()
+        with self.registry.span("restore.load_vmm", member=self.member):
+            self.gm = (GuestMemoryFile(self.base, layout)
+                       if layout is not None
+                       else GuestMemoryFile.open(self.base))
+            self.monitor = Monitor(self.gm, self.base, self.reap,
+                                   mode=self.mode, cache=self.cache)
+            if self.exec_restore is not None:
+                self.exec_restore()
         self.timings.load_vmm_s = self.clock() - t0
-        self._span("load_vmm", t0, self.timings.load_vmm_s)
+        self._observe("load_vmm", self.timings.load_vmm_s)
 
     def connect(self) -> None:
         t0 = self.clock()
-        self.connector()
+        with self.registry.span("restore.connect", member=self.member):
+            self.connector()
         self.timings.connection_s = self.clock() - t0
-        self._span("connect", t0, self.timings.connection_s)
+        self._observe("connect", self.timings.connection_s)
 
     def ws_fetch(self, group: int = 1):
         """Fetch the working set (REAP prefetch phase, read half).
@@ -327,29 +340,32 @@ class RestorePipeline:
         mon = self.monitor
         if mon.mode != "prefetch":
             return None
-        cfg = self.reap
         t0 = self.clock()
         try:
-            if not cfg.use_ws_file:
-                pages = [int(p) for p in np.load(trace_path(self.base))]
-                data, hit = None, False
-            else:
-                split = (self._split_fetch(group)
-                         if cfg.overlap_install else None)
-                if split is not None:
-                    pages, data, hit = split
-                elif cfg.share_ws_cache:
-                    pages, data, hit = (self.cache or WS_CACHE).fetch(
-                        self.base, cfg, group=group)
-                else:
-                    pages, data = _read_ws(self.base, cfg)
-                    hit = False
+            with self.registry.span("restore.ws_fetch") as span:
+                pages, data, hit = self._fetch(group)
+                span.set_metadata(cache_hit=hit)
         except FileNotFoundError:
             mon.mode = "record"          # record dropped under us: re-record
             return None
         self.timings.ws_fetch_s = self.clock() - t0
-        self._span("ws_fetch", t0, self.timings.ws_fetch_s, cache_hit=hit)
+        self._observe("ws_fetch", self.timings.ws_fetch_s)
         return pages, data, hit
+
+    def _fetch(self, group: int):
+        """``(pages, data, cache_hit)`` from the design point ``reap``
+        selects: traced pages only, a split prefix read, the shared WS
+        cache, or a private read."""
+        cfg = self.reap
+        if not cfg.use_ws_file:
+            pages = [int(p) for p in np.load(trace_path(self.base))]
+            return pages, None, False
+        split = self._split_fetch(group) if cfg.overlap_install else None
+        if split is not None:
+            return split
+        if cfg.share_ws_cache:
+            return (self.cache or WS_CACHE).fetch(self.base, cfg, group=group)
+        return (*_read_ws(self.base, cfg), False)
 
     def _split_fetch(self, group: int):
         """Overlapped fetch: eagerly read only the hot-prefix span of the
@@ -431,33 +447,31 @@ class RestorePipeline:
             return
         pages, data, hit = fetched
         t0 = self.clock()
-        if data is None:
-            self.monitor.arena.touch_pages(
-                pages, parallel=max(self.reap.parallel_faults, 1))
-        else:
+        k = len(pages)
+        if data is not None:
             k = (self._split_k if self._split_k is not None
                  else self.hot_count(len(pages)))
-            self.monitor.arena.install_span(
-                pages[:k], memoryview(data)[:k * PAGE])
-            if k < len(pages):
-                self.timings.install_s = self.clock() - t0
-                self._span("install", t0, self.timings.install_s,
-                           hot_pages=k, total_pages=len(pages))
-                self._mark_prefetched(len(pages), hit)
-                if self._tail_fetch is not None:
-                    # split fetch: the tail's bytes arrive in the background
-                    self._start_tail(pages[k:],
-                                     fetch=self._tail_rows(k, pages[k:]))
-                else:
-                    tail_block = np.frombuffer(
-                        data, dtype=np.uint8,
-                        count=len(pages) * PAGE).reshape(-1, PAGE)[k:]
-                    self._start_tail(pages[k:], tail_block)
-                return
+        with self.registry.span("restore.install", member=self.member,
+                                pages=k):
+            if data is None:
+                self.monitor.arena.touch_pages(
+                    pages, parallel=max(self.reap.parallel_faults, 1))
+            else:
+                self.monitor.arena.install_span(
+                    pages[:k], memoryview(data)[:k * PAGE])
         self.timings.install_s = self.clock() - t0
-        self._span("install", t0, self.timings.install_s,
-                   total_pages=len(pages))
+        self._observe("install", self.timings.install_s)
         self._mark_prefetched(len(pages), hit)
+        if k == len(pages):
+            return
+        if self._tail_fetch is not None:
+            # split fetch: the tail's bytes arrive in the background
+            self._start_tail(pages[k:], fetch=self._tail_rows(k, pages[k:]))
+        else:
+            tail_block = np.frombuffer(
+                data, dtype=np.uint8,
+                count=len(pages) * PAGE).reshape(-1, PAGE)[k:]
+            self._start_tail(pages[k:], tail_block)
 
     def install_block(self, sorted_pages: np.ndarray, block: np.ndarray,
                       hit: bool, *, ws_fetch_s: float = 0.0,
@@ -473,13 +487,13 @@ class RestorePipeline:
         None defers the tail's bytes to ``tail_fetch`` (split fetch).
         """
         t0 = self.clock()
-        self.monitor.arena.install_block(sorted_pages, block)
+        with self.registry.span("restore.install", member=self.member,
+                                pages=len(sorted_pages)):
+            self.monitor.arena.install_block(sorted_pages, block)
         self.timings.install_s = self.clock() - t0
         self.timings.ws_fetch_s = ws_fetch_s
-        self._span("ws_fetch", t0, self.timings.ws_fetch_s,
-                   cache_hit=hit, group_share=True)
-        self._span("install", t0, self.timings.install_s,
-                   batched=True, total_pages=len(sorted_pages))
+        self._observe("ws_fetch", self.timings.ws_fetch_s)
+        self._observe("install", self.timings.install_s)
         n_total = len(sorted_pages)
         if tail is not None and len(tail[0]):
             n_total += len(tail[0])
@@ -491,8 +505,7 @@ class RestorePipeline:
         t0 = self.clock()
         fn()
         self.timings.materialize_s = self.clock() - t0
-        self._span("materialize", t0, self.timings.materialize_s)
-        self._trace.finish()             # materialize ends the cold start
+        self._observe("materialize", self.timings.materialize_s)
 
     def _mark_prefetched(self, n_pages: int, hit: bool) -> None:
         # keep the monitor's view consistent so finish() computes the
@@ -543,83 +556,91 @@ class RestoreBatch:
         if len(bases) > 1:
             raise ValueError(f"restore batch spans bases {sorted(bases)}")
         self.pipes = pipes
+        for i, p in enumerate(pipes):
+            p.member = i
         self.fuse_s = 0.0                # the shared gather pass, once
 
     def run(self) -> "RestoreBatch":
         pipes = self.pipes
-        try:
-            layout = None
-            for p in pipes:
-                p.load_vmm(layout=layout)
-                layout = p.gm.layout     # manifest parsed once per group
-            for p in pipes:
-                p.connect()
-            leader = pipes[0]
-            fetched = leader.ws_fetch(group=len(pipes))
-            if fetched is None:
-                # record/vanilla mode — or the §7.2 fallback; every member
-                # must agree (followers may have resolved 'prefetch' from a
-                # record that a concurrent re-record has since dropped)
-                if leader.monitor.mode == "record":
-                    for p in pipes[1:]:
-                        p.monitor.mode = "record"
-                return self
-            pages, data, hit = fetched
-            if len(pipes) == 1 or data is None:
-                # single restore, or the "Parallel PFs" design point where
-                # every arena demand-reads its own pages (nothing to fuse)
+        with pipes[0].registry.span("restore.group", n=len(pipes)) as span:
+            try:
+                self._run(span)
+            except BaseException:
                 for p in pipes:
-                    p.install(fetched)
-                return self
-            t0 = leader.clock()
-            if leader._split_k is not None:
-                # the leader's fetch split: ``data`` holds only the hot
-                # prefix span.  Fuse just the prefix; every member's tail
-                # resolves the full WS in the background (the per-pipe
-                # fetch closures collapse to one read via the single-flight
-                # cache, the rest hit the fresh entry)
-                k = leader._split_k
-                sorted_hot, hot_block = fuse_ws_block(
-                    pages[:k], data, engine=leader.reap.fuse_engine)
-                self.fuse_s = leader.clock() - t0
-                fetch_s = leader.timings.ws_fetch_s + self.fuse_s
-                tail_pages = np.asarray(pages[k:], dtype=np.int64)
-                for p in pipes:
-                    p.install_block(
-                        sorted_hot, hot_block, hit, ws_fetch_s=fetch_s,
-                        tail=(tail_pages, None),
-                        tail_fetch=leader._tail_rows(k, tail_pages))
-                return self
-            sorted_pages, block = fuse_ws_block(
-                pages, data, engine=leader.reap.fuse_engine)
+                    p.close()            # never leak half-restored arenas
+                raise
+        return self
+
+    def _run(self, span) -> None:
+        pipes = self.pipes
+        layout = None
+        for p in pipes:
+            p.load_vmm(layout=layout)
+            layout = p.gm.layout     # manifest parsed once per group
+        for p in pipes:
+            p.connect()
+        leader = pipes[0]
+        fetched = leader.ws_fetch(group=len(pipes))
+        if fetched is None:
+            # record/vanilla mode — or the §7.2 fallback; every member
+            # must agree (followers may have resolved 'prefetch' from a
+            # record that a concurrent re-record has since dropped)
+            if leader.monitor.mode == "record":
+                for p in pipes[1:]:
+                    p.monitor.mode = "record"
+            return
+        pages, data, hit = fetched
+        span.set_metadata(pages=len(pages))
+        if len(pipes) == 1 or data is None:
+            # single restore, or the "Parallel PFs" design point where
+            # every arena demand-reads its own pages (nothing to fuse)
+            for p in pipes:
+                p.install(fetched)
+            return
+        t0 = leader.clock()
+        if leader._split_k is not None:
+            # the leader's fetch split: ``data`` holds only the hot
+            # prefix span.  Fuse just the prefix; every member's tail
+            # resolves the full WS in the background (the per-pipe
+            # fetch closures collapse to one read via the single-flight
+            # cache, the rest hit the fresh entry)
+            k = leader._split_k
+            sorted_hot, hot_block = fuse_ws_block(
+                pages[:k], data, engine=leader.reap.fuse_engine)
             self.fuse_s = leader.clock() - t0
-            # the fuse pass and the fetch sit on every member's critical
-            # path — charge them to each report like follower waits were
             fetch_s = leader.timings.ws_fetch_s + self.fuse_s
-            k_hot = leader.hot_count(len(pages))
-            if k_hot < len(pages):
-                # overlapped group restore: the hot set is the fault-order
-                # head of the trace; split the ascending fused block by
-                # membership so each member eagerly scatters only the
-                # prefix and backgrounds the rest
-                hot = set(int(p) for p in pages[:k_hot])
-                mask = np.fromiter((int(p) in hot for p in sorted_pages),
-                                   dtype=bool, count=len(sorted_pages))
-                hot_pages, hot_block = sorted_pages[mask], block[mask]
-                tail_pages, tail_block = sorted_pages[~mask], block[~mask]
-                for p in pipes:
-                    p.install_block(hot_pages, hot_block, hit,
-                                    ws_fetch_s=fetch_s,
-                                    tail=(tail_pages, tail_block))
-            else:
-                for p in pipes:
-                    p.install_block(sorted_pages, block, hit,
-                                    ws_fetch_s=fetch_s)
-            return self
-        except BaseException:
+            tail_pages = np.asarray(pages[k:], dtype=np.int64)
             for p in pipes:
-                p.close()                # never leak half-restored arenas
-            raise
+                p.install_block(
+                    sorted_hot, hot_block, hit, ws_fetch_s=fetch_s,
+                    tail=(tail_pages, None),
+                    tail_fetch=leader._tail_rows(k, tail_pages))
+            return
+        sorted_pages, block = fuse_ws_block(
+            pages, data, engine=leader.reap.fuse_engine)
+        self.fuse_s = leader.clock() - t0
+        # the fuse pass and the fetch sit on every member's critical
+        # path — charge them to each report like follower waits were
+        fetch_s = leader.timings.ws_fetch_s + self.fuse_s
+        k_hot = leader.hot_count(len(pages))
+        if k_hot < len(pages):
+            # overlapped group restore: the hot set is the fault-order
+            # head of the trace; split the ascending fused block by
+            # membership so each member eagerly scatters only the
+            # prefix and backgrounds the rest
+            hot = set(int(p) for p in pages[:k_hot])
+            mask = np.fromiter((int(p) in hot for p in sorted_pages),
+                               dtype=bool, count=len(sorted_pages))
+            hot_pages, hot_block = sorted_pages[mask], block[mask]
+            tail_pages, tail_block = sorted_pages[~mask], block[~mask]
+            for p in pipes:
+                p.install_block(hot_pages, hot_block, hit,
+                                ws_fetch_s=fetch_s,
+                                tail=(tail_pages, tail_block))
+        else:
+            for p in pipes:
+                p.install_block(sorted_pages, block, hit,
+                                ws_fetch_s=fetch_s)
 
     def stage_seconds(self) -> dict:
         """Aggregate per-stage seconds across the group (+ the fuse pass)."""

@@ -35,10 +35,12 @@ import jax
 
 from ..configs.base import ModelConfig
 from ..core import ReapConfig, run_invocation
+from ..core.executor import place, placed_bytes
 from ..core.reap import ColdStartReport, StageTimings
 from ..core.restore import RestoreBatch, RestorePipeline
 from ..models import get_family
 from ..nn import spec as nnspec
+from ..telemetry import TELEMETRY
 
 
 class State(enum.Enum):
@@ -200,8 +202,10 @@ class FunctionInstance:
         tw0, tws0 = stats.tail_waits, stats.tail_wait_seconds
         t0 = self.perf_clock()
         if self._warm_params is not None:
-            logits = ExecutableCache.get(self.cfg)(self._warm_params, batch)
-            logits.block_until_ready()
+            with TELEMETRY.span("executor.forward"):
+                logits = ExecutableCache.get(self.cfg)(self._warm_params,
+                                                       batch)
+                logits.block_until_ready()
         else:
             logits, _ = run_invocation(self.cfg, self.monitor.arena, batch,
                                        parallel=parallel_faults,
@@ -255,24 +259,30 @@ class FunctionInstance:
 
     def make_warm(self):
         """Promote to a memory-resident (warm) instance: materialize params
-        as device arrays so later invocations skip the arena entirely."""
-        fam = get_family(self.cfg)
-        specs = fam.param_specs(self.cfg)
-        self.monitor.arena.touch_pages(
-            sorted(set().union(*[set(self.monitor.arena.layout.pages_of(f"params/{p}"))
-                                 for p, _ in nnspec.tree_paths(specs)])))
-        self._warm_params = nnspec.map_leaves(
-            lambda p, s: jax.device_put(
-                self.monitor.arena.tensor(f"params/{p}", fault=False),
-                self.device), specs)
+        as device arrays so later invocations skip the arena entirely.
+        The placement is dispatched, not waited for: the first warm
+        forward waits for it."""
+        arena = self.monitor.arena
+        specs = get_family(self.cfg).param_specs(self.cfg)
+        with TELEMETRY.span("instance.make_warm"):
+            with TELEMETRY.span("executor.touch") as span:
+                pages = sorted(set().union(*[
+                    set(arena.layout.pages_of(f"params/{p}"))
+                    for p, _ in nnspec.tree_paths(specs)]))
+                arena.touch_pages(pages)
+                span.set_metadata(pages=len(pages))
+            with TELEMETRY.span("executor.placement") as span:
+                self._warm_params = place(arena, specs, self.device)
+                span.set_metadata(bytes=placed_bytes(self._warm_params))
 
     def finish_cold(self) -> dict:
-        if self.monitor.mode == "vanilla":
-            stats = self.monitor.arena.stats
-            return {"mode": "vanilla", "n_faults": stats.n_faults,
-                    "fault_s": stats.fault_seconds,
-                    "resident_bytes": self.monitor.arena.resident_bytes}
-        return self.monitor.finish()
+        with TELEMETRY.span("instance.finish_cold"):
+            if self.monitor.mode == "vanilla":
+                stats = self.monitor.arena.stats
+                return {"mode": "vanilla", "n_faults": stats.n_faults,
+                        "fault_s": stats.fault_seconds,
+                        "resident_bytes": self.monitor.arena.resident_bytes}
+            return self.monitor.finish()
 
     def reclaim(self):
         """Unconditional teardown (caller must know the instance is not

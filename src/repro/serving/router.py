@@ -22,6 +22,7 @@ timing segment next to the paper's load/connect/prefetch/processing split.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -56,12 +57,17 @@ class RouterConfig:
 class Invocation:
     """Future for one accepted invocation."""
 
+    _seqs = itertools.count()
+
     def __init__(self, name: str, batch: dict, force_cold: bool,
                  *, clock=time.perf_counter):
         self.name = name
         self.batch = batch
         self.force_cold = force_cold
         self.t_submit = clock()
+        #: submit order, unique in the process (ties a dispatch's spans
+        #: to its invocation)
+        self.seq = next(Invocation._seqs)
         self.queue_s = 0.0
         self.group_hint = 1              # set at dispatch: cold-group size
         self._done = threading.Event()
@@ -293,11 +299,13 @@ class Router:
             inv.queue_s = self.clock() - inv.t_submit
             self.registry.observe("router.queue_s", inv.queue_s)
             try:
-                out, rep = self.orch.invoke(inv.name, inv.batch,
-                                            force_cold=inv.force_cold,
-                                            group_hint=inv.group_hint)
-                rep = dataclasses.replace(rep, queue_s=inv.queue_s)
-                inv._resolve(out, rep)
+                with self.registry.span("router.dispatch", inv=inv.seq,
+                                        queue_s=inv.queue_s):
+                    out, rep = self.orch.invoke(inv.name, inv.batch,
+                                                force_cold=inv.force_cold,
+                                                group_hint=inv.group_hint)
+                    rep = dataclasses.replace(rep, queue_s=inv.queue_s)
+                    inv._resolve(out, rep)
             except BaseException as e:  # propagate to the waiter, keep serving
                 inv._fail(e)
             finally:

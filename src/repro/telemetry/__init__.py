@@ -1,5 +1,5 @@
-"""Fleet control room: process-wide metrics registry, cold-start trace
-spans, and a periodic stats snapshotter.
+"""Fleet control room: process-wide metrics registry, cold-start spans on
+the profiler's timeline, and a periodic stats snapshotter.
 
 Three tiers (see README "Control room"):
 
@@ -8,8 +8,9 @@ Three tiers (see README "Control room"):
                                                        -> scripts/bench_compare.py --history (CI gate)
 
 * :class:`MetricsRegistry` — lock-light counters / gauges / fixed-bucket
-  histograms plus a :class:`Trace`/:class:`Span` API for per-invocation
-  cold-start traces.  A process-wide default lives at
+  histograms plus :meth:`~MetricsRegistry.span`, a
+  ``jax.profiler.TraceAnnotation`` that puts a cold-start stage on the
+  profiler's timeline beside the device ops.  A process-wide default lives at
   :data:`repro.telemetry.TELEMETRY`; emitters take ``registry=None`` and
   fall back to it, and :meth:`MetricsRegistry.disable` turns every
   emission into a no-op (the overhead A/B in the scalability benchmark).
@@ -27,8 +28,6 @@ from .registry import (  # noqa: F401
     Gauge,
     Histogram,
     MetricsRegistry,
-    Span,
-    Trace,
 )
 from .schema import LEGACY_ALIASES, SAMPLE_KEYS, canonicalize  # noqa: F401
 from .snapshot import StatsSnapshotter, TelemetryConfig  # noqa: F401
@@ -39,8 +38,6 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "Span",
-    "Trace",
     "StatsSnapshotter",
     "TelemetryConfig",
     "LEGACY_ALIASES",

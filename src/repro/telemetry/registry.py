@@ -1,4 +1,4 @@
-"""Lock-light metrics registry + cold-start trace spans.
+"""Lock-light metrics registry + cold-start spans on the profiler's clock.
 
 Design constraints, in order:
 
@@ -9,26 +9,25 @@ Design constraints, in order:
 2. **Disable == no-op.**  :meth:`MetricsRegistry.disable` flips one
    boolean checked before any work; the scalability benchmark's
    telemetry-overhead A/B toggles it.
-3. **StageTimings stays the stage-seconds sink (REP005).**  Restore
-   spans *read* their durations from the just-written ``StageTimings``
-   fields — the registry never computes a stage duration itself.
+3. **StageTimings stays the stage-seconds sink (REP005).**  A span
+   (:meth:`MetricsRegistry.span`) is a ``jax.profiler.TraceAnnotation``:
+   the profiler stamps it, on the clock of the device ops it writes into
+   the same ``.xplane.pb``, and keeps it only while a profiler session
+   runs.  The registry never computes a stage duration itself.
 4. **No direct ``time.*`` reads.**  Emitters pass their own injected
-   clock's timestamps in; the registry only stores what it is handed.
+   clock's values in; a span reads no Python clock (outside a profiler
+   session it is one inactive C++ ``TraceMe``).
 """
 from __future__ import annotations
 
 import bisect
 import math
-import dataclasses
 import threading
-from collections import deque
 
 __all__ = [
     "Counter",
     "Gauge",
     "Histogram",
-    "Span",
-    "Trace",
     "MetricsRegistry",
     "TELEMETRY",
 ]
@@ -144,54 +143,6 @@ class Histogram:
             }
 
 
-@dataclasses.dataclass
-class Span:
-    """One timed stage inside a :class:`Trace`.  ``start_s`` is in the
-    emitting component's clock domain; ``duration_s`` is read from the
-    component's own timing sink (StageTimings for restore stages)."""
-
-    name: str
-    start_s: float
-    duration_s: float
-    attrs: dict = dataclasses.field(default_factory=dict)
-
-    def to_dict(self) -> dict:
-        d = {"name": self.name, "start_s": self.start_s,
-             "duration_s": self.duration_s}
-        if self.attrs:
-            d["attrs"] = dict(self.attrs)
-        return d
-
-
-class Trace:
-    """A per-invocation span list (e.g. one cold start).  Built by one
-    thread; the registry keeps a bounded ring of finished traces."""
-
-    __slots__ = ("kind", "attrs", "spans", "_registry")
-
-    def __init__(self, kind: str, attrs: dict | None = None,
-                 registry: "MetricsRegistry | None" = None) -> None:
-        self.kind = kind
-        self.attrs = dict(attrs or {})
-        self.spans: list[Span] = []
-        self._registry = registry
-
-    def add(self, name: str, start_s: float, duration_s: float,
-            **attrs) -> Span:
-        span = Span(name, float(start_s), float(duration_s), attrs)
-        self.spans.append(span)
-        return span
-
-    def finish(self) -> None:
-        """Hand the completed trace to the owning registry's ring."""
-        if self._registry is not None:
-            self._registry._record_trace(self)
-
-    def to_dict(self) -> dict:
-        return {"kind": self.kind, "attrs": dict(self.attrs),
-                "spans": [s.to_dict() for s in self.spans]}
-
-
 class _Noop:
     """Stand-in returned by a disabled registry; swallows everything."""
 
@@ -206,30 +157,43 @@ class _Noop:
     def observe(self, v: float) -> None:
         pass
 
-    def add(self, name, start_s, duration_s, **attrs) -> None:
-        pass
-
-    def finish(self) -> None:
-        pass
-
 
 _NOOP = _Noop()
 
 
-class MetricsRegistry:
-    """Process-wide named metrics + trace ring.
+class _NullSpan:
+    """The span of a disabled registry: enters, exits (never swallowing an
+    exception) and drops its metadata."""
 
-    The creation lock (``_mu``) guards only the name->metric maps and the
-    trace ring; per-metric updates take the metric's own leaf lock.  All
-    public methods are safe from any thread.
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **attrs) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+_annotation = None       # jax.profiler.TraceAnnotation, imported on first use
+
+
+class MetricsRegistry:
+    """Process-wide named metrics + profiler spans.
+
+    The creation lock (``_mu``) guards only the name->metric maps;
+    per-metric updates take the metric's own leaf lock.  All public
+    methods are safe from any thread.
     """
 
-    def __init__(self, *, trace_ring: int = 256, enabled: bool = True) -> None:
+    def __init__(self, *, enabled: bool = True) -> None:
         self._mu = threading.Lock()
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
-        self._traces: deque[Trace] = deque(maxlen=trace_ring)
         self.enabled = bool(enabled)
 
     # -- toggles --------------------------------------------------------
@@ -280,29 +244,27 @@ class MetricsRegistry:
     def observe(self, name: str, v: float) -> None:
         self.histogram(name).observe(v)
 
-    # -- traces ---------------------------------------------------------
+    # -- spans ----------------------------------------------------------
 
-    def trace(self, kind: str, **attrs) -> Trace:
+    def span(self, name: str, **attrs):
+        """Context manager: one event named ``name`` on the profiler's
+        timeline, from enter to exit, with ``attrs`` as its stats (more
+        may be added on the way with ``set_metadata``).  It nests under
+        the span open on the same thread.  A disabled registry returns
+        one shared null span."""
         if not self.enabled:
-            return _NOOP  # type: ignore[return-value]
-        return Trace(kind, attrs, registry=self)
-
-    def _record_trace(self, trace: Trace) -> None:
-        with self._mu:
-            self._traces.append(trace)
-
-    def traces(self, kind: str | None = None) -> list[Trace]:
-        with self._mu:
-            ts = list(self._traces)
-        if kind is None:
-            return ts
-        return [t for t in ts if t.kind == kind]
+            return _NULL_SPAN
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        return _annotation(name, **attrs)
 
     # -- export ---------------------------------------------------------
 
     def collect(self) -> dict:
-        """Stable-keyed snapshot of every metric (no traces: those are
-        bounded-ring debugging payloads, exported separately)."""
+        """Stable-keyed snapshot of every metric (spans live in the
+        profiler's trace, not here)."""
         with self._mu:
             counters = dict(self._counters)
             gauges = dict(self._gauges)
@@ -315,12 +277,11 @@ class MetricsRegistry:
         }
 
     def reset(self) -> None:
-        """Drop every metric and trace (benchmark arm isolation)."""
+        """Drop every metric (benchmark arm isolation)."""
         with self._mu:
             self._counters.clear()
             self._gauges.clear()
             self._histograms.clear()
-            self._traces.clear()
 
 
 #: Process-wide default registry.  Emitters take ``registry=None`` and

@@ -1,5 +1,5 @@
 """Telemetry layer (src/repro/telemetry/): registry semantics, cold-start
-trace spans, the stats snapshotter on a fake clock (no sleeps — REP004
+spans on the profiler's timeline, the stats snapshotter on a fake clock (no sleeps — REP004
 thread shutdown is the only wall-clock moment), the canonical stat-key
 schema, forecast-profile persistence round trips, and the bench trend
 gate (scripts/bench_compare.py --history).
@@ -61,54 +61,94 @@ def test_disabled_registry_is_noop():
     reg.disable()
     reg.inc("hits", 100)
     reg.observe("lat_s", 1.0)
-    reg.trace("cold_start").add("install", 0.0, 1.0)
+    with reg.span("restore.install", member=0) as span:
+        span.set_metadata(pages=3)
+    assert reg.span("a") is reg.span("b", x=1)    # one shared null span
     reg.enable()
     snap = reg.collect()
     assert snap["counters"]["hits"] == 2
     assert "lat_s" not in snap["histograms"]
-    assert reg.traces("cold_start") == []
 
 
 def test_reset_clears_everything():
     reg = MetricsRegistry()
     reg.inc("a")
     reg.observe("b", 1.0)
-    t = reg.trace("cold_start")
-    t.add("s", 0.0, 1.0)
-    t.finish()
+    with reg.span("s"):
+        pass
     reg.reset()
     snap = reg.collect()
-    assert snap["counters"] == {} and snap["histograms"] == {}
-    assert reg.traces() == []
+    assert snap == {"enabled": True, "counters": {}, "gauges": {},
+                    "histograms": {}}
 
 
-# -- trace spans ---------------------------------------------------------
+# -- spans on the profiler's timeline -----------------------------------
 
-def test_trace_spans_record_and_ring_bound():
-    reg = MetricsRegistry(trace_ring=4)
-    for i in range(10):
-        t = reg.trace("cold_start", base=f"fn{i}")
-        t.add("load_vmm", 0.0, 0.010)
-        t.add("install", 0.010, 0.020, batched=True)
-        t.finish()
-    traces = reg.traces("cold_start")
-    assert len(traces) == 4                   # ring bound holds
-    d = traces[-1].to_dict()
-    assert d["kind"] == "cold_start"
-    assert d["attrs"]["base"] == "fn9"
-    names = [s["name"] for s in d["spans"]]
-    assert names == ["load_vmm", "install"]
-    assert d["spans"][1]["attrs"]["batched"] is True
-    assert d["spans"][1]["duration_s"] == pytest.approx(0.020)
+def _profiled(log_dir, body):
+    """Run ``body`` inside a profiler session; the host events of the
+    trace as {name: [(start_ns, end_ns, stats)]}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)[0]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats)))
+    return out
 
 
-def test_unfinished_trace_not_listed():
-    reg = MetricsRegistry()
-    t = reg.trace("cold_start")
-    t.add("s", 0.0, 1.0)
-    assert reg.traces("cold_start") == []
-    t.finish()
-    assert len(reg.traces("cold_start")) == 1
+def test_span_lands_in_the_profiler_trace_only_when_enabled(tmp_path):
+    on, off = MetricsRegistry(), MetricsRegistry(enabled=False)
+
+    def body():
+        with on.span("telemetry.outer", inv=7, queue_s=0.25) as span:
+            with on.span("telemetry.inner", bytes=4096):
+                pass
+            span.set_metadata(pages=3)
+        with off.span("telemetry.off", inv=8):
+            pass
+
+    events = _profiled(tmp_path, body)
+    assert "telemetry.off" not in events
+    [(s0, t0, outer)] = events["telemetry.outer"]
+    [(s1, t1, inner)] = events["telemetry.inner"]
+    assert outer == {"inv": 7, "queue_s": 0.25, "pages": 3}
+    assert inner == {"bytes": 4096}
+    assert s0 <= s1 <= t1 <= t0               # nested under its caller
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_a_span_whose_body_raises_closes_and_reraises(tmp_path, enabled):
+    reg = MetricsRegistry(enabled=enabled)
+
+    def body():
+        with pytest.raises(ValueError, match="boom"):
+            with reg.span("telemetry.raises", member=1):
+                raise ValueError("boom")
+        with reg.span("telemetry.after"):
+            pass
+
+    events = _profiled(tmp_path, body)
+    if not enabled:
+        assert "telemetry.raises" not in events
+        return
+    [(s0, t0, attrs)] = events["telemetry.raises"]
+    [(s1, _, _)] = events["telemetry.after"]
+    assert attrs == {"member": 1}
+    assert t0 <= s1                            # closed before the next
 
 
 # -- snapshotter ---------------------------------------------------------
