@@ -16,6 +16,7 @@ baseline), mirroring userfaultfd semantics at framework level
 """
 from __future__ import annotations
 
+import concurrent.futures as cf
 import dataclasses
 import json
 import mmap
@@ -26,7 +27,46 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from ..telemetry import TELEMETRY
+
 PAGE = 4096
+
+#: a block install of at least this many pages (64 MB) copies on several
+#: threads: the copy into a fresh arena is mostly first-touch page faults,
+#: which the kernel serves on several threads at once.  A smaller block
+#: (a tail chunk, a transport pull) copies on the calling thread.
+PARALLEL_MIN_PAGES = 16384
+#: pages one copy task copies (32 MB)
+COPY_CHUNK_PAGES = 8192
+
+
+def _copy_workers() -> int:
+    """Threads of a parallel copy: the host's cores, at most 8."""
+    return max(1, min(8, os.cpu_count() or 1))
+
+
+def _copy_rows(arr: np.ndarray, tgt: np.ndarray, src, i0: int,
+               i1: int) -> None:
+    """``arr[tgt[i]] = src[i]`` for ``i0 <= i < i1``: one row scatter."""
+    arr[tgt[i0:i1]] = src[i0:i1]
+
+
+def _copy_parallel(arr: np.ndarray, tgt: np.ndarray, src) -> int:
+    """``arr[tgt] = src`` in chunks of ``COPY_CHUNK_PAGES`` rows on several
+    threads (numpy releases the GIL in its copy loops).  Returns the
+    number of threads."""
+    n = len(tgt)
+    starts = range(0, n, COPY_CHUNK_PAGES)
+    workers = min(_copy_workers(), len(starts))
+    if workers:
+        # the with-exit joins every chunk before a failure propagates
+        with cf.ThreadPoolExecutor(workers,
+                                   thread_name_prefix="arena-copy") as pool:
+            list(pool.map(
+                lambda i: _copy_rows(arr, tgt, src, i,
+                                     min(i + COPY_CHUNK_PAGES, n)),
+                starts))
+    return workers
 
 
 def _align(n: int, a: int = PAGE) -> int:
@@ -244,6 +284,10 @@ class InstanceArena:
         # installer's notify instead of reading disk
         self._cv = threading.Condition(self._lock)
         self._pending: set[int] = set()
+        # pages a large block install is copying outside the lock; a fault
+        # on one waits for that install like one on a pending page
+        self._copying = np.zeros(self.layout.n_pages, dtype=bool)
+        self._n_copying = 0
         #: liveness backstop for waiters — a tail stuck past this falls
         #: through to the disk-fault path regardless of the pending marker
         self.pending_wait_s = 30.0
@@ -269,9 +313,9 @@ class InstanceArena:
             missing = [p for p in pages if not self.resident[p]]
             if not missing:
                 return 0
-            if self._pending:
+            if self._pending or self._n_copying:
                 waited = self._wait_pending_locked(
-                    [p for p in missing if p in self._pending])
+                    [p for p in missing if self._in_flight(p)])
                 if waited:
                     missing = [p for p in pages if not self.resident[p]]
                     if not missing:
@@ -298,6 +342,9 @@ class InstanceArena:
                 self._cv.notify_all()
             return len(missing)
 
+    def _in_flight(self, p: int) -> bool:
+        return p in self._pending or bool(self._copying[p])
+
     def _wait_pending_locked(self, pend: list[int]) -> bool:
         """Wait (``_cv`` held) for in-flight installs covering ``pend``;
         returns True when any wait actually happened."""
@@ -306,7 +353,7 @@ class InstanceArena:
         t0 = time.perf_counter()
         deadline = t0 + self.pending_wait_s
         while (not self._closed
-               and any(p in self._pending for p in pend)):
+               and any(self._in_flight(p) for p in pend)):
             left = deadline - time.perf_counter()
             if left <= 0:
                 break
@@ -326,10 +373,12 @@ class InstanceArena:
                 int(p) for p in pages if not self.resident[p])
 
     def install_pending(self, page_indices, block) -> int:
-        """Install one chunk of pending pages (vectorized scatter) and wake
-        fault waiters.  Returns pages actually installed."""
+        """Install one chunk of pending pages (a block install on the
+        calling thread) and wake fault waiters.  Returns pages actually
+        installed."""
         with self._cv:
-            n = self.install_block(page_indices, block)
+            n = self._install_block_locked(
+                np.asarray(page_indices, dtype=np.int64), block)
             self._pending.difference_update(int(p) for p in page_indices)
             self._cv.notify_all()
             return n
@@ -362,8 +411,6 @@ class InstanceArena:
             return len(self._pending)
 
     def _fault_parallel(self, pages: list[int], workers: int) -> None:
-        import concurrent.futures as cf
-
         def job(chunk):
             src = PageSource(self.gm.mem_path, o_direct=True)
             try:
@@ -388,25 +435,65 @@ class InstanceArena:
                     self.resident[p] = True
             self.stats.n_pages_installed += len(page_indices)
 
-    def install_block(self, page_indices, block) -> int:
-        """Fused eager install: one vectorized scatter of a prefetched page
-        block (``block[i]`` -> page ``page_indices[i]``), instead of
-        ``install_span``'s per-page loop.  ``block`` is a ``(n, PAGE)``
-        uint8 array (the output of a fused gather pass — restore.py); pages
-        already resident are skipped, byte-identically to ``install_span``.
-        Returns the number of pages actually installed."""
-        with self._lock:
-            idx = np.asarray(page_indices, dtype=np.int64)
-            missing = ~self.resident[idx]
-            tgt = idx[missing]
-            if len(tgt):
-                arr = np.frombuffer(
-                    self.buf, dtype=np.uint8,
-                    count=self.layout.n_pages * PAGE).reshape(-1, PAGE)
-                arr[tgt] = block[missing]
-                self.resident[tgt] = True
-            self.stats.n_pages_installed += len(idx)
-            return int(len(tgt))
+    def install_block(self, page_indices, block) -> tuple[int, int]:
+        """Fused eager install of a prefetched page block (``block[i]`` ->
+        page ``page_indices[i]``), instead of ``install_span``'s per-page
+        loop.  ``block`` is a ``(n, PAGE)`` uint8 array (the output of a
+        fused gather pass — restore.py); pages already resident are
+        skipped, byte-identically to ``install_span``.  A block of at
+        least ``PARALLEL_MIN_PAGES`` strictly ascending pages is copied
+        outside the arena lock, in chunks on several threads; a fault on
+        one of its pages meanwhile waits for the install.  Returns the
+        number of pages actually installed and the threads that copied
+        them (1: the calling thread)."""
+        idx = np.asarray(page_indices, dtype=np.int64)
+        if (len(idx) < PARALLEL_MIN_PAGES or _copy_workers() < 2
+                or not (np.diff(idx) > 0).all()):
+            with self._lock:
+                return self._install_block_locked(idx, block), 1
+        with self._cv:
+            tgt, mask = self._missing(idx)
+            self._copying[tgt] = True
+            self._n_copying += 1
+            arr = self._pages_array()
+        copied = False
+        try:
+            src = block if mask is None else block[mask]
+            workers = _copy_parallel(arr, tgt, src)
+            copied = True
+        finally:
+            with self._cv:
+                self._copying[tgt] = False
+                self._n_copying -= 1
+                if copied:
+                    self.resident[tgt] = True
+                    self.stats.n_pages_installed += len(idx)
+                self._cv.notify_all()
+        TELEMETRY.inc("arena.install.parallel_pages", len(tgt))
+        return int(len(tgt)), workers
+
+    def _missing(self, idx: np.ndarray):
+        """The pages of ``idx`` not yet resident, and the mask of their
+        rows (None when none is resident: the block needs no copy)."""
+        missing = ~self.resident[idx]
+        if missing.all():
+            return idx, None
+        return idx[missing], missing
+
+    def _pages_array(self) -> np.ndarray:
+        return np.frombuffer(self.buf, dtype=np.uint8,
+                             count=self.layout.n_pages * PAGE
+                             ).reshape(-1, PAGE)
+
+    def _install_block_locked(self, idx: np.ndarray, block) -> int:
+        """``install_block`` on the calling thread, arena lock held."""
+        tgt, mask = self._missing(idx)
+        if len(tgt):
+            src = block if mask is None else block[mask]
+            _copy_rows(self._pages_array(), tgt, src, 0, len(tgt))
+            self.resident[tgt] = True
+        self.stats.n_pages_installed += len(idx)
+        return int(len(tgt))
 
     # -- tensor access ------------------------------------------------------
 
@@ -438,6 +525,7 @@ class InstanceArena:
             self._closed = True
             # no waiter may hang on a pending marker past close
             self._pending.clear()
+            self._copying[:] = False
             self._cv.notify_all()
             self.source.close()
             self.view.release()

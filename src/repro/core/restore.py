@@ -16,7 +16,8 @@ loops.  :class:`RestoreBatch` restores all N as **one** staged operation:
   * one manifest parse (the layout is shared across the group's arenas),
   * one WS fetch (a single cache transaction instead of leader+followers),
   * one fused page-gather pass producing an ascending-page install block,
-  * N vectorized block installs (one scatter per arena, no per-page loop).
+  * N block installs (one row scatter, in chunks on several threads for
+    a large block; no per-page loop).
 
 The fuse step is the ``page_gather`` kernel's job description: reorder the
 trace-order WS into the contiguous block the installs want.  On a TPU
@@ -477,7 +478,7 @@ class RestorePipeline:
                       hit: bool, *, ws_fetch_s: float = 0.0,
                       tail: tuple[np.ndarray, np.ndarray | None] | None = None,
                       tail_fetch=None) -> None:
-        """Fused group install: one vectorized scatter of the shared block.
+        """Fused group install: one block install of the shared block.
 
         ``ws_fetch_s`` charges this instance its share of the group's
         single fetch (every member waited on it, like followers used to
@@ -488,8 +489,10 @@ class RestorePipeline:
         """
         t0 = self.clock()
         with self.registry.span("restore.install", member=self.member,
-                                pages=len(sorted_pages)):
-            self.monitor.arena.install_block(sorted_pages, block)
+                                pages=len(sorted_pages)) as span:
+            _, workers = self.monitor.arena.install_block(sorted_pages,
+                                                          block)
+            span.set_metadata(workers=workers)
         self.timings.install_s = self.clock() - t0
         self.timings.ws_fetch_s = ws_fetch_s
         self._observe("ws_fetch", self.timings.ws_fetch_s)
@@ -540,7 +543,7 @@ class RestoreBatch:
 
     All pipelines must target the same ``base``.  The group performs one
     manifest parse, one WS fetch, and one fused gather pass; every member
-    then installs the shared block with one vectorized scatter.  With
+    then installs the shared block with one block install.  With
     ``len(pipes) == 1`` the batch degrades to the plain per-page pipeline
     (identical semantics to an unbatched restore).
 

@@ -15,10 +15,13 @@ from fakeclock import FakeClock
 
 from repro.configs import SMOKES
 from repro.core import ReapConfig
+from repro.core import arena as arena_mod
 from repro.core import reap as reap_mod
 from repro.core.arena import PAGE, ArenaLayout, GuestMemoryFile, InstanceArena
 from repro.core.reap import WS_CACHE, WSCache
-from repro.core.restore import (RestoreBatch, RestorePipeline, fuse_ws_block)
+from repro.core.restore import (RestoreBatch, RestorePipeline, TailInstall,
+                                fuse_ws_block)
+from repro.telemetry import TELEMETRY
 from repro.launch import steps
 from repro.serving import Orchestrator, Router, RouterConfig
 
@@ -63,25 +66,189 @@ def small_recorded(tmp_path):
 # -- fused install parity ----------------------------------------------
 
 
-@pytest.mark.parametrize("engine", ["numpy", "pallas"])
-def test_fused_block_install_matches_install_span(small_recorded, engine):
-    """The fused gather + vectorized scatter must be byte-identical to the
-    per-page install_span path, for both fuse engines."""
-    gm = small_recorded
-    pages, data = reap_mod._read_ws(gm.base, ReapConfig())
+def _blank_gm(path, n_pages: int) -> GuestMemoryFile:
+    """A guest memory file of ``n_pages`` zero pages (sparse on disk)."""
+    layout = ArenaLayout.build([("w", (n_pages * PAGE,), "uint8", "serve")])
+    gm = GuestMemoryFile(str(path), layout)
+    with open(gm.mem_path, "wb") as f:
+        f.truncate(layout.total_bytes)
+    return gm
 
-    a_span = InstanceArena(GuestMemoryFile.open(gm.base))
-    a_span.install_span(pages, data)
-    a_block = InstanceArena(GuestMemoryFile.open(gm.base))
-    sorted_pages, block = fuse_ws_block(pages, data, engine=engine)
-    installed = a_block.install_block(sorted_pages, block)
 
-    assert installed == len(pages)
+def _small_copies(monkeypatch) -> None:
+    """Copy blocks of 64 pages and more on several threads, 16 pages a task,
+    so that a small arena has many chunks."""
+    monkeypatch.setattr(arena_mod, "PARALLEL_MIN_PAGES", 64)
+    monkeypatch.setattr(arena_mod, "COPY_CHUNK_PAGES", 16)
+
+
+#: runs of 1 to 280 pages, cut by chunk edges
+GAPPED = np.r_[0:50, 51:70:2, 70:200, 203:205, 300:500:2, 520:800]
+#: a few long runs, below the real threshold: the calling thread
+RUNS = np.r_[10:100, 150:300, 301, 400:800]
+#: block-install cases beside the two fuse engines: (arena pages, target
+#: pages, pages resident beforehand, small parallel-copy sizes)
+INSTALL_CASES = {
+    # contiguous, above the real parallel threshold
+    "contiguous": (arena_mod.PARALLEL_MIN_PAGES + 1000,
+                   np.arange(arena_mod.PARALLEL_MIN_PAGES + 1000), [], False),
+    "gapped": (800, GAPPED, [], True),
+    # partly resident: the masked path, on several threads
+    "resident": (800, GAPPED, [0, 1, 2, 61, 63, 71, 400, 530, 799], True),
+    # below the real threshold: the calling thread
+    "serial": (800, RUNS, [], False),
+}
+
+
+@pytest.mark.parametrize("case", ["numpy", "pallas", *INSTALL_CASES])
+def test_fused_block_install_matches_install_span(request, tmp_path,
+                                                  monkeypatch, case):
+    """The block install must be byte-identical to the per-page
+    install_span path, in ``resident`` and ``n_pages_installed`` too: for
+    a fused block of each engine, and for contiguous, gapped, partly
+    resident and small blocks on each copy path."""
+    before = []
+    parallel = False
+    if case in ("numpy", "pallas"):
+        gm = request.getfixturevalue("small_recorded")
+        span_pages, data = reap_mod._read_ws(gm.base, ReapConfig())
+        pages, block = fuse_ws_block(span_pages, data, engine=case)
+    else:
+        n_arena, pages, before, small = INSTALL_CASES[case]
+        parallel = len(pages) >= (64 if small else
+                                  arena_mod.PARALLEL_MIN_PAGES)
+        if small:
+            _small_copies(monkeypatch)
+        gm = _blank_gm(tmp_path / "fn", n_arena)
+        block = np.random.default_rng(3).integers(
+            0, 256, (len(pages), PAGE), dtype=np.uint8)
+        span_pages, data = pages, block.tobytes()
+
+    a_span = InstanceArena(gm)
+    a_block = InstanceArena(gm)
+    for a in (a_span, a_block):
+        # resident beforehand with other bytes: both paths must skip them
+        a.install_span(before, b"\xaa" * PAGE * len(before))
+    a_span.install_span(span_pages, data)
+    installed, workers = a_block.install_block(pages, block)
+
+    assert installed == len(set(pages.tolist()) - set(before))
     np.testing.assert_array_equal(np.asarray(a_span.resident),
                                   np.asarray(a_block.resident))
+    assert (a_span.stats.n_pages_installed
+            == a_block.stats.n_pages_installed)
     assert bytes(a_span.view) == bytes(a_block.view)   # full arena bytes
+    assert (workers > 1) == (parallel and arena_mod._copy_workers() > 1)
     a_span.close()
     a_block.close()
+
+
+def _gate_copies(monkeypatch):
+    """Hold every chunk of a parallel copy until ``go`` is set; ``started``
+    is set when the first one begins."""
+    started, go = threading.Event(), threading.Event()
+    real = arena_mod._copy_rows
+
+    def gated(*args):
+        started.set()
+        assert go.wait(10)
+        real(*args)
+
+    monkeypatch.setattr(arena_mod, "_copy_rows", gated)
+    return started, go
+
+
+def _thread(fn, *args):
+    """A started thread running ``fn``; what it raised lands in the
+    returned list."""
+    errors = []
+
+    def run():
+        try:
+            fn(*args)
+        except Exception as e:  # noqa: BLE001 - reported to the test
+            errors.append(e)
+
+    t = threading.Thread(target=run)
+    t.start()
+    return t, errors
+
+
+def test_fault_on_a_page_of_an_inflight_block_install_waits(tmp_path,
+                                                           monkeypatch):
+    """A parallel copy runs outside the arena lock; a fault on one of its
+    pages meanwhile waits for the install instead of reading disk."""
+    _small_copies(monkeypatch)
+    started, go = _gate_copies(monkeypatch)
+    arena = InstanceArena(_blank_gm(tmp_path / "fn", 256))
+    pages = np.arange(256)
+    block = np.random.default_rng(4).integers(0, 256, (256, PAGE),
+                                              dtype=np.uint8)
+    installer, inst_err = _thread(arena.install_block, pages, block)
+    assert started.wait(10)
+    toucher, touch_err = _thread(arena.touch_pages, [100])
+    toucher.join(0.3)
+    assert toucher.is_alive()            # parked on the in-flight copy
+    go.set()
+    installer.join(10)
+    toucher.join(10)
+    assert not installer.is_alive() and not toucher.is_alive()
+    assert inst_err == [] and touch_err == []
+    assert arena.stats.n_faults == 0     # no disk read
+    assert arena.stats.tail_waits == 1
+    assert arena.resident.all()
+    got = np.frombuffer(bytes(arena.view), np.uint8).reshape(-1, PAGE)
+    np.testing.assert_array_equal(got, block)
+    arena.close()
+
+
+@pytest.mark.parametrize("when", ["during", "after"])
+def test_close_around_a_block_install_parks_no_waiter(tmp_path, monkeypatch,
+                                                      when):
+    """``close()`` during a parallel copy wakes the fault parked on it, and
+    the install still ends; after the install no marker is left."""
+    _small_copies(monkeypatch)
+    started, go = _gate_copies(monkeypatch)
+    arena = InstanceArena(_blank_gm(tmp_path / "fn", 256))
+    block = np.zeros((256, PAGE), np.uint8)
+    if when == "after":
+        go.set()
+    installer, inst_err = _thread(arena.install_block, np.arange(256), block)
+    assert started.wait(10)
+    if when == "during":
+        toucher, _ = _thread(arena.touch_pages, [7])
+        toucher.join(0.3)
+        assert toucher.is_alive()
+        arena.close()
+        toucher.join(10)
+        assert not toucher.is_alive()    # woken by close, not parked
+        go.set()
+    installer.join(10)
+    assert not installer.is_alive() and inst_err == []
+    assert arena._n_copying == 0 and not arena._copying.any()
+    assert arena.pending_count == 0
+    arena.close()
+
+
+def test_tail_install_pending_copies_on_its_own_thread(tmp_path,
+                                                      monkeypatch):
+    """``TailInstall`` still lands its chunks through ``install_pending``,
+    serially under the arena lock: none of its pages is copied in parallel."""
+    _small_copies(monkeypatch)
+    arena = InstanceArena(_blank_gm(tmp_path / "fn", 800))
+    block = np.random.default_rng(5).integers(0, 256, (len(GAPPED), PAGE),
+                                              dtype=np.uint8)
+    counter = TELEMETRY.counter("arena.install.parallel_pages")
+    n0 = counter.value
+    tail = TailInstall(arena, GAPPED, block, deadline_s=60.0)
+    tail.wait(30)
+    assert tail.done_at is not None and not tail.demoted
+    assert arena.pending_count == 0
+    assert arena.resident[GAPPED].all() and arena.resident.sum() == len(GAPPED)
+    assert counter.value == n0
+    got = np.frombuffer(bytes(arena.view), np.uint8).reshape(-1, PAGE)
+    np.testing.assert_array_equal(got[GAPPED], block)
+    arena.close()
 
 
 def test_fuse_engines_agree_and_scatter_kernel_roundtrips(small_recorded):

@@ -151,6 +151,48 @@ def test_a_span_whose_body_raises_closes_and_reraises(tmp_path, enabled):
     assert t0 <= s1                            # closed before the next
 
 
+def test_restore_install_span_reports_copy_workers(tmp_path, monkeypatch):
+    """Each ``restore.install`` span carries the copy's ``workers``;
+    ``arena.install.parallel_pages`` counts the pages of a block
+    at or above the parallel threshold, and none of one below it."""
+    import numpy as np
+
+    from repro.core import arena as arena_mod
+    from repro.core.arena import PAGE, ArenaLayout, GuestMemoryFile
+    from repro.core.restore import RestorePipeline
+    from repro.telemetry import TELEMETRY
+
+    monkeypatch.setattr(arena_mod, "PARALLEL_MIN_PAGES", 64)
+    monkeypatch.setattr(arena_mod, "COPY_CHUNK_PAGES", 16)
+    layout = ArenaLayout.build([("w", (512 * PAGE,), "uint8", "serve")])
+    gm = GuestMemoryFile(str(tmp_path / "fn"), layout)
+    with open(gm.mem_path, "wb") as f:
+        f.truncate(layout.total_bytes)
+    blocks = [np.r_[0:100, 200:300], np.arange(400, 440)]   # 200, 40 pages
+    counter = TELEMETRY.counter("arena.install.parallel_pages")
+    n0 = counter.value
+    pipes = []
+
+    def body():
+        for member, pages in enumerate(blocks):
+            p = RestorePipeline(gm.base, mode="vanilla")
+            p.member = member
+            p.load_vmm(layout=layout)
+            pipes.append(p)
+            p.install_block(pages, np.ones((len(pages), PAGE), np.uint8),
+                            hit=True)
+
+    events = _profiled(tmp_path / "prof", body)
+    for p in pipes:
+        p.close()
+    spans = {s[2]["member"]: s[2] for s in events["restore.install"]}
+    big, small = spans[0], spans[1]
+    assert big["pages"] == 200
+    assert big["workers"] == min(arena_mod._copy_workers(), 200 // 16 + 1)
+    assert small == {"member": 1, "pages": 40, "workers": 1}
+    assert counter.value - n0 == (200 if big["workers"] > 1 else 0)
+
+
 # -- snapshotter ---------------------------------------------------------
 
 def test_snapshotter_fakeclock_cadence():
