@@ -7,7 +7,14 @@ file of its own, found by the name ``BENCHMARK.json`` gives it:
 - ``configs/<config>.json``: the sizes as run (every key that names a
   field of the program's ``ModelConfig`` sets it), the program's
   architecture name, the reference module and the limits of the
-  comparison;
+  comparison.  The program's registered config, ``ARCHS[program]``, is
+  the published one.  ``reduced`` lists the program config keys the file
+  cuts from it (the same list as the ``BENCHMARK.json`` entry's); a file
+  whose ``reduced`` is not empty also gives ``published``, each cut key's
+  published value, and a non-empty ``deployment``, what the cut stands
+  for: the stage of how many chips, or the chip's share of what.  Only
+  depth, the experts held, the vocabulary and the lists that follow the
+  layers may be cut, never a width (``cuts.py`` checks it);
 - ``reference/<module>.py``: the plain float32 forward of that model, its
   parameter table and its FLOP count;
 - ``traffic/<traffic>.json``: the parameters of the one generator
@@ -125,13 +132,25 @@ def model_keys() -> set[str]:
 
 def program_config(config: dict):
     """The program's ModelConfig, with the configuration file's sizes (a
-    ``dtype`` given by its name, as ``"bfloat16"``)."""
+    ``dtype`` given by its name, as ``"bfloat16"``; a list as a tuple, so
+    that the config stays hashable)."""
     import jax.numpy as jnp
     from repro.configs import ARCHS
-    over = {k: config[k] for k in model_keys() & set(config)}
+    over = {k: tuple(v) if isinstance(v, list) else v
+            for k, v in config.items() if k in model_keys()}
     if "dtype" in over:
         over["dtype"] = getattr(jnp, over["dtype"])
     return dataclasses.replace(ARCHS[config["program"]], **over)
+
+
+def published_config(program: str) -> dict:
+    """The program's registered config of ``program`` as a configuration
+    file states it: each model key, the dtype by its name."""
+    from repro.configs import ARCHS
+    cfg = ARCHS[program]
+    out = {k: getattr(cfg, k) for k in model_keys()}
+    out["dtype"] = np.dtype(out["dtype"]).name
+    return out
 
 
 def check_table(cfg, table: list[tuple]) -> None:

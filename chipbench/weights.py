@@ -14,10 +14,19 @@ weights and optimizer moments): see ``PERF.md`` for why.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 #: standard deviation of every leaf drawn at random
 SCALE = 0.02
+#: Mamba2's initialization of its SSM (Dao & Gu, arXiv:2405.21060;
+#: ``A_init_range``, ``dt_min``, ``dt_max``, ``dt_init_floor``, the same
+#: as Zamba2's ``time_step_min``, ``time_step_max``, ``time_step_floor``):
+#: A is drawn from U[1, 16], the step size log-uniformly from [1e-3, 0.1]
+#: and raised to at least 1e-4
+A_INIT_RANGE = (1.0, 16.0)
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 0.1, 1e-4
 
 
 def seed_words(seed: int) -> tuple[np.uint32, np.uint32]:
@@ -29,7 +38,9 @@ def seed_words(seed: int) -> tuple[np.uint32, np.uint32]:
 
 def _leaf(key, shape, dtype, init):
     """One leaf of init law ``init``: ``normal`` (N(0, SCALE)),
-    ``zeros`` or ``ones``."""
+    ``zeros``, ``ones``, ``mamba2_a_log`` (log A, A ~ U[1, 16]) or
+    ``mamba2_dt_bias`` (softplus^-1 of the step size: Mamba2's ``dt_bias``).
+    Each law draws in float32 from ``key``."""
     import jax
     import jax.numpy as jnp
 
@@ -40,6 +51,15 @@ def _leaf(key, shape, dtype, init):
     if init == "normal":
         x = jax.random.normal(key, shape, jnp.float32)
         return (x * SCALE).astype(dtype)
+    if init == "mamba2_a_log":
+        a = jax.random.uniform(key, shape, jnp.float32, *A_INIT_RANGE)
+        return jnp.log(a).astype(dtype)
+    if init == "mamba2_dt_bias":
+        u = jax.random.uniform(key, shape, jnp.float32, math.log(DT_MIN),
+                               math.log(DT_MAX))
+        dt = jnp.maximum(jnp.exp(u), DT_FLOOR)
+        # softplus^-1(dt) = log(exp(dt) - 1), accurate for small dt
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
     raise ValueError(f"unknown init law {init!r}")
 
 

@@ -9,11 +9,11 @@ import sys
 
 import pytest
 
-from chipbench import generator, harness
+from chipbench import cuts, generator, harness
 
 ROOT = harness.ROOT
 MAN = harness.manifest()
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+NAME = cuts.NAME
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in MAN["workloads"]]
 
@@ -80,6 +80,9 @@ def test_every_cell_reports_setup_an_end_to_end_and_a_layer_metric(cell):
 
 
 def test_configurations_are_whole_and_named_once():
+    """Each configuration differs from its program's published config in
+    the keys it declares cut, and only there, each cut within its floor
+    (``cuts.py``)."""
     files = [c["file"] for c in MAN["configs"]]
     assert len(files) == len(set(files))
     used = {w["config"] for w in MAN["workloads"]}
@@ -87,7 +90,8 @@ def test_configurations_are_whole_and_named_once():
         assert c["name"] in used
         assert c["file"].startswith("chipbench/")
         config = harness.load_json(os.path.join(ROOT, c["file"]))
-        assert config["reduced"] == c["reduced"] == []
+        published = harness.published_config(config["program"])
+        assert cuts.problems(published, config, c["reduced"]) == [], c["name"]
 
 
 def _run_py(cwd, env_extra):

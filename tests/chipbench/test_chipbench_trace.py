@@ -21,8 +21,10 @@ def cpu_trace(tmp_path_factory):
     x = jnp.ones((256, 256))
     step(x).block_until_ready()
     trace_reduce.start(log_dir)
+    # long beside any stall of a loaded test host, so that this gap is
+    # the longest of the trace
     with jax.profiler.TraceAnnotation("chipbench.test_idle"):
-        time.sleep(0.05)
+        time.sleep(0.3)
     for _ in range(3):
         step(x).block_until_ready()
     jax.profiler.stop_trace()
@@ -54,7 +56,7 @@ def test_breakdown_names_ops_and_what_the_host_did(cpu_trace):
     secs = [s for _, s in b["idle_gaps"]]
     assert secs == sorted(secs, reverse=True)
     assert b["idle_gaps"][0][0] == "chipbench.test_idle"
-    assert b["idle_gaps"][0][1] >= 0.04
+    assert b["idle_gaps"][0][1] >= 0.29
 
 
 def _synthetic():
